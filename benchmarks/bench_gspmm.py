@@ -169,6 +169,8 @@ def main() -> None:
     ap.add_argument("--out", default="BENCH_gspmm.json")
     args = ap.parse_args()
     force_host_devices(args.devices)    # before the first jax import
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     gspmm_bench(n=args.n, feat_dim=args.feat_dim, workers=args.workers,
                 devices=args.devices, epochs=args.epochs,
                 repeat=args.repeat, out=args.out, gate=args.gate)

@@ -170,6 +170,8 @@ def main() -> None:
     ap.add_argument("--out", default="BENCH_pipeline.json")
     args = ap.parse_args()
     force_host_devices(max(args.devices))   # before the first jax import
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     pipeline_bench(n=args.n, workers=args.workers,
                    device_counts=tuple(args.devices), n_iters=args.iters,
                    repeat=args.repeat, out=args.out, gate=args.gate)

@@ -57,4 +57,6 @@ def run(scale=20_000):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

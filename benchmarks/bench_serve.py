@@ -191,6 +191,8 @@ def main() -> None:
     ap.add_argument("--out", default="BENCH_serve.json")
     args = ap.parse_args()
     force_host_devices(args.devices)    # before the first jax import
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     serve_bench(n=args.n, workers=args.workers, devices=args.devices,
                 batch=args.batch, rounds=args.rounds, churn=args.churn,
                 repeat=args.repeat, ppr_iters=args.ppr_iters,

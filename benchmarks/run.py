@@ -221,6 +221,8 @@ def main() -> None:
     args = ap.parse_args()
     if args.smoke or args.graph_bench:
         force_host_devices(8)      # before the first jax import
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         smoke(args.out)
         return

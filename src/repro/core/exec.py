@@ -92,7 +92,6 @@ from typing import Callable, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import bsp
@@ -2335,11 +2334,11 @@ def build_sharded(pg, make_step: Callable, state0, max_supersteps: int,
         return bsp.run(make_step(sg), st0, max_supersteps, record_history,
                        raw_totals=True, pipeline=pipeline)
 
-    fn = shard_map(inner, mesh=mesh,
-                   in_specs=(arr_specs, st_specs),
-                   out_specs=(st_specs, _acc_specs(stats_shape), P(),
-                              hist_specs),
-                   check_rep=False)
+    fn = jax.shard_map(inner, mesh=mesh,
+                       in_specs=(arr_specs, st_specs),
+                       out_specs=(st_specs, _acc_specs(stats_shape), P(),
+                                  hist_specs),
+                       check_vma=False)
     return jax.jit(fn), (arrays, state0), stats_shape
 
 
@@ -2408,8 +2407,8 @@ def build_apply(pg, make_fn: Callable, args: Tuple, devices: int = 1,
         sg = _make_sg(meta, arrs)
         return make_fn(sg)(*a)
 
-    fn = shard_map(inner, mesh=mesh, in_specs=(arr_specs, in_specs),
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(inner, mesh=mesh, in_specs=(arr_specs, in_specs),
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(fn), arrays
 
 

@@ -35,8 +35,8 @@ Two runtime paths:
 
 Kernel dispatch: the Pallas kernel is compiled for real on TPU; on CPU the
 block-layout jnp reference (same math, same layout) executes the plan, and
-``set_kernel_mode('pallas')`` forces interpret-mode Pallas for wiring
-tests.
+``set_kernel_mode('pallas')`` forces the kernel, which the backend check
+in ``segment_combine_blocks`` runs interpreted off TPU (wiring tests).
 """
 from __future__ import annotations
 
@@ -267,9 +267,7 @@ def _combine_rows(packed: jnp.ndarray, row_local: jnp.ndarray, op: str,
     if mode == "ref":
         out = segment_combine_blocks_ref(packed, row_local, op, nb)
     else:
-        out = segment_combine_blocks(
-            packed, row_local, op, nb,
-            interpret=jax.default_backend() != "tpu")
+        out = segment_combine_blocks(packed, row_local, op, nb)
     # The kernel's float min/max identities are finite sentinels
     # (VMEM-friendly); map no-hit slots back to the channel identities so
     # the combined blocks compare exactly against the dense path.  Integer
@@ -461,6 +459,7 @@ def sort_by_worker_target(worker: jnp.ndarray, t: jnp.ndarray):
     ``worker * n_pad + target`` composite key that could overflow int32.
     Returns (order, sorted worker, sorted target, first-of-segment mask);
     a segment is one distinct (worker, target) pair."""
+    worker, t = jnp.asarray(worker), jnp.asarray(t)
     order1 = jnp.argsort(t, stable=True)
     order = order1[jnp.argsort(worker[order1], stable=True)]
     ws, ts = worker[order], t[order]

@@ -124,6 +124,11 @@ class PartitionedGraph:
     per-worker (M+1,) row offsets; ``eg_src``/``all_src`` hold *global*
     source slots and ``mir_edst`` *global* destination ids (the worker of
     an edge is ``id // n_loc``), masks are all-True (no padding exists).
+
+    Edge-sized arrays (``eg_*``, ``all_*``, ``mir_e*``, ``*_pw``) stay host
+    NumPy: the sharded executor slices them per device and each device
+    receives only its slice, so no device ever holds the whole edge set.
+    Vertex-sized arrays are jnp.
     """
     n: int
     M: int
@@ -133,25 +138,25 @@ class PartitionedGraph:
     inv_perm: np.ndarray
 
     # Ch_msg edges (from non-mirrored sources):
-    eg_src: jnp.ndarray       # (M, E_loc) local src slot | (E_lo,) global
-    eg_dst: jnp.ndarray       # (M, E_loc) global dst id (pad: 0) | (E_lo,)
-    eg_mask: jnp.ndarray      # (M, E_loc) bool | (E_lo,) all-True
-    eg_w: jnp.ndarray         # (M, E_loc) float32 | (E_lo,)
+    eg_src: np.ndarray       # (M, E_loc) local src slot | (E_lo,) global
+    eg_dst: np.ndarray       # (M, E_loc) global dst id (pad: 0) | (E_lo,)
+    eg_mask: np.ndarray      # (M, E_loc) bool | (E_lo,) all-True
+    eg_w: np.ndarray         # (M, E_loc) float32 | (E_lo,)
 
     # full adjacency (mirrored + not), for algorithms that need all edges:
-    all_src: jnp.ndarray      # (M, A_loc) | (E,) global
-    all_dst: jnp.ndarray
-    all_mask: jnp.ndarray
-    all_w: jnp.ndarray
+    all_src: np.ndarray      # (M, A_loc) | (E,) global
+    all_dst: np.ndarray
+    all_mask: np.ndarray
+    all_w: np.ndarray
 
     # mirror structures:
     mir_ids: jnp.ndarray      # (n_mir,) global ids of mirrored vertices (pad n)
     mir_slot_of: jnp.ndarray  # (M, n_loc) index into mir_ids or -1
     mir_nworkers: jnp.ndarray # (n_mir,) #workers holding a mirror (Thm 1 count)
-    mir_esrc: jnp.ndarray     # (M, ME_loc) index into mir_ids | (ME,)
-    mir_edst: jnp.ndarray     # (M, ME_loc) local dst slot | (ME,) global dst
-    mir_emask: jnp.ndarray    # (M, ME_loc) | (ME,) all-True
-    mir_ew: jnp.ndarray       # (M, ME_loc) | (ME,)
+    mir_esrc: np.ndarray     # (M, ME_loc) index into mir_ids | (ME,)
+    mir_edst: np.ndarray     # (M, ME_loc) local dst slot | (ME,) global dst
+    mir_emask: np.ndarray    # (M, ME_loc) | (ME,) all-True
+    mir_ew: np.ndarray       # (M, ME_loc) | (ME,)
 
     deg: jnp.ndarray          # (M, n_loc) out-degree
     vmask: jnp.ndarray        # (M, n_loc) real-vertex mask
@@ -172,9 +177,9 @@ class PartitionedGraph:
     phys_eg_off: Optional[np.ndarray] = None   # (M_phys+1,) refined offsets
     phys_all_off: Optional[np.ndarray] = None
     phys_mir_off: Optional[np.ndarray] = None
-    eg_pw: Optional[jnp.ndarray] = None        # per-edge physical shard ids
-    all_pw: Optional[jnp.ndarray] = None
-    mir_pw: Optional[jnp.ndarray] = None
+    eg_pw: Optional[np.ndarray] = None        # per-edge physical shard ids
+    all_pw: Optional[np.ndarray] = None
+    mir_pw: Optional[np.ndarray] = None
 
     # (M, M) distinct (source worker, destination vertex) pair counts of
     # the full adjacency: pair_counts[s, d] bounds the combined messages
@@ -475,27 +480,24 @@ def partition(g: Graph, M: int, tau: Optional[int] = None,
         phys_all = _refine_offsets(all_off, k)
         phys_mir = _refine_offsets(hb, k)
         pids = np.arange(M_phys, dtype=np.int32)
-        eg_pw = jnp.asarray(np.repeat(pids, np.diff(phys_eg)))
-        all_pw = jnp.asarray(np.repeat(pids, np.diff(phys_all)))
-        mir_pw_np = np.repeat(pids, np.diff(phys_mir))
-        mir_pw = jnp.asarray(mir_pw_np)
+        eg_pw = np.repeat(pids, np.diff(phys_eg))
+        all_pw = np.repeat(pids, np.diff(phys_all))
+        mir_pw = np.repeat(pids, np.diff(phys_mir))
         if len(hsrc):
             # Theorem-1 accounting at shard granularity: a mirrored vertex
             # is broadcast once per *physical shard* hosting its edges
-            spair = np.unique(es_all.astype(np.int64) * M_phys + mir_pw_np)
+            spair = np.unique(es_all.astype(np.int64) * M_phys + mir_pw)
             nworkers = np.bincount(spair // M_phys, minlength=n_mir)
 
     return PartitionedGraph(
         n=g.n, M=M, n_loc=n_loc, tau=int(tau_eff), perm=perm, inv_perm=inv,
-        eg_src=jnp.asarray(eg_src), eg_dst=jnp.asarray(eg_dst),
-        eg_mask=jnp.asarray(eg_mask), eg_w=jnp.asarray(eg_w),
-        all_src=jnp.asarray(all_src), all_dst=jnp.asarray(all_dst),
-        all_mask=jnp.asarray(all_mask), all_w=jnp.asarray(all_w),
+        eg_src=eg_src, eg_dst=eg_dst, eg_mask=eg_mask, eg_w=eg_w,
+        all_src=all_src, all_dst=all_dst, all_mask=all_mask, all_w=all_w,
         mir_ids=jnp.asarray(mir_ids_arr),
         mir_slot_of=jnp.asarray(mir_slot_of),
         mir_nworkers=jnp.asarray(nworkers),
-        mir_esrc=jnp.asarray(mir_esrc), mir_edst=jnp.asarray(mir_edst),
-        mir_emask=jnp.asarray(mir_emask), mir_ew=jnp.asarray(mir_ew),
+        mir_esrc=mir_esrc, mir_edst=mir_edst, mir_emask=mir_emask,
+        mir_ew=mir_ew,
         deg=jnp.asarray(deg_pad), vmask=jnp.asarray(vmask),
         layout=layout, eg_off=eg_off, all_off=all_off, mir_eoff=mir_eoff,
         balance=balance, split_factor=split_factor, M_phys=M_phys,
@@ -731,15 +733,12 @@ def fold_delta(pg: PartitionedGraph, delta: EdgeDelta) -> PartitionedGraph:
         # no vertex is mirrored before or after the fold: Ch_msg IS the
         # full adjacency (exactly as in a fresh partition) and every
         # mirror field is the empty sentinel pg already carries
-        src_j = jnp.asarray(na_src)
-        dst_j = jnp.asarray(na_dst)
-        w_j = jnp.asarray(na_w)
-        mask_j = jnp.asarray(np.ones(e_new, bool))
+        mask = np.ones(e_new, bool)
         return PartitionedGraph(
             n=pg.n, M=M, n_loc=n_loc, tau=tau_eff, perm=perm,
             inv_perm=pg.inv_perm,
-            eg_src=src_j, eg_dst=dst_j, eg_mask=mask_j, eg_w=w_j,
-            all_src=src_j, all_dst=dst_j, all_mask=mask_j, all_w=w_j,
+            eg_src=na_src, eg_dst=na_dst, eg_mask=mask, eg_w=na_w,
+            all_src=na_src, all_dst=na_dst, all_mask=mask, all_w=na_w,
             mir_ids=pg.mir_ids, mir_slot_of=pg.mir_slot_of,
             mir_nworkers=pg.mir_nworkers, mir_esrc=pg.mir_esrc,
             mir_edst=pg.mir_edst, mir_emask=pg.mir_emask,
@@ -839,22 +838,18 @@ def fold_delta(pg: PartitionedGraph, delta: EdgeDelta) -> PartitionedGraph:
     return PartitionedGraph(
         n=pg.n, M=M, n_loc=n_loc, tau=tau_eff, perm=perm,
         inv_perm=pg.inv_perm,
-        eg_src=jnp.asarray(na_src[lo_e]),
-        eg_dst=jnp.asarray(na_dst[lo_e]),
-        eg_mask=jnp.asarray(np.ones(int(lo_e.sum()), bool)),
-        eg_w=jnp.asarray(na_w[lo_e]),
-        all_src=jnp.asarray(na_src),
-        all_dst=jnp.asarray(na_dst),
-        all_mask=jnp.asarray(np.ones(e_new, bool)),
-        all_w=jnp.asarray(na_w),
+        eg_src=na_src[lo_e], eg_dst=na_dst[lo_e],
+        eg_mask=np.ones(int(lo_e.sum()), bool), eg_w=na_w[lo_e],
+        all_src=na_src, all_dst=na_dst, all_mask=np.ones(e_new, bool),
+        all_w=na_w,
         mir_ids=jnp.asarray(mir_ids_arr),
         mir_slot_of=jnp.asarray(mir_idx.astype(np.int32)
                                 .reshape(M, n_loc)),
         mir_nworkers=jnp.asarray(nworkers),
-        mir_esrc=jnp.asarray(mir_idx[m_gsrc].astype(np.int32)),
-        mir_edst=jnp.asarray(m_gdst.astype(np.int32)),
-        mir_emask=jnp.asarray(np.ones(n_k + n_p, bool)),
-        mir_ew=jnp.asarray(m_w),
+        mir_esrc=mir_idx[m_gsrc].astype(np.int32),
+        mir_edst=m_gdst.astype(np.int32),
+        mir_emask=np.ones(n_k + n_p, bool),
+        mir_ew=m_w,
         deg=jnp.asarray(deg_new.astype(np.int32).reshape(M, n_loc)),
         vmask=pg.vmask,
         layout="csr", eg_off=eg_off_n, all_off=new_off, mir_eoff=hb_n,

@@ -15,11 +15,14 @@ Bq*d + 2*Bk*d + Bq*Bk floats (default 512x512 blocks, d<=256: ~1.5MB).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG = -2.0 ** 30
 
@@ -69,8 +72,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          causal: bool = True, window: int = 0,
                          bq: int = 512, bk: int = 512,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: Optional[bool] = None) -> jax.Array:
     """q: (BH, Sq, d); k/v: (BKV, Sk, d) with BH % BKV == 0 (GQA)."""
+    interpret = resolve_interpret(interpret)
     BH, Sq, d = q.shape
     BKV, Sk, _ = k.shape
     n_rep = BH // BKV

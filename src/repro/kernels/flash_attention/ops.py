@@ -13,7 +13,7 @@ from repro.kernels.flash_attention.ref import flash_attention_ref
 @partial(jax.jit, static_argnames=("causal", "window", "bq", "bk",
                                    "use_kernel", "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=0, bq=512, bk=512,
-                    use_kernel=True, interpret=True):
+                    use_kernel=True, interpret=None):
     """q: (B, Sq, H, hd); k/v: (B, Sk, K, hd).  Returns (B, Sq, H, hd).
 
     Row b*H + h of the flattened q maps to kv row b*K + h // (H/K):

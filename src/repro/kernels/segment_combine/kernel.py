@@ -3,39 +3,54 @@
 TPU adaptation of the paper's per-message hash-table combiner (DESIGN.md §2):
 a CPU combiner groups messages with a hash table — serial, pointer-chasing,
 hostile to the VPU/MXU.  Here messages are pre-sorted by destination block
-(host-side, once per graph) and each grid step combines one edge block into
-one destination block with a *dense* compare/accumulate in VMEM:
+(host-side, once per graph) and each packed row combines its Eb edge lanes
+into one Nb-wide destination block with a *dense* compare/reduce in VMEM:
 
-    hit[e, n]  = (idx[e] == n)               (Eb x Nb in VMEM)
+    hit[e, n]  = (idx[e] == n)               (Eb x Nb per row)
     out[n]     = op_e  hit ? val[e] : identity
 
-For op='sum' this is literally a one-hot matmul -> MXU; min/max run on the
-VPU.  Block sizes default to (Eb=512, Nb=256): hit matrix = 512KB f32,
-well inside the ~16MB VMEM budget, and Nb is a multiple of the 128-lane
-register width.
+Every op (sum, min, max; float or int) is the same select-and-reduce on
+the VPU — integer sums never reach the MXU, which has no int32 path on
+v5e, and f32 sums add in full f32 (no bf16 matmul passes).  A grid step
+takes an aligned tile of ``ROWS`` = 8 packed rows: an (8, Eb) value/index
+block and an (8, Nb) output block, so both trailing block dims meet the
+(8, 128) tiling rule (or equal the array's own dims).  The wrapper pads
+the row count to a multiple of 8 with idx = -1 rows, which never hit.
+Eb and Nb are the plan's row and block widths (``core/plan.py``: Eb in
+[8, 512], Nb = 128 on TPU); at Eb=512, Nb=128 the (8, Eb, Nb) select
+tile is 2 MB of f32, well inside the 16 MB scoped-VMEM default.
+
+Feature-blocked (n_blocks, Eb, F) payloads run the same tile combine per
+row over 8-feature chunks: the wrapper moves features ahead of edges,
+(n_blocks, F, Eb), so each chunk is an (8, Eb) tile exactly like eight
+scalar rows, and the (F, Nb) result is moved back after the call.
 
 Dtype handling: float blocks use the finite sentinels NEG/POS as min/max
 identities (VMEM-friendly; the plan layer maps them back to +-inf);
 integer blocks use the dtype's iinfo bounds, which double as the exact
 channel identities — id-carrying algorithms (Hash-Min, S-V) combine in
 int32 so vertex ids above 2^24 stay exactly representable.
+
+``interpret=None`` runs the compiled kernel on TPU and the Pallas
+interpreter on any other backend (CPU tests); pass a bool to force one.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 NEG = -3.0e38
 POS = 3.0e38
 
-# largest (eb, nb, fc) min/max select tile the vector kernel materializes
-# in VMEM at once: 512 x 256 x 8 x 4B = 4 MB, well inside the ~16 MB core
-# budget alongside the shared (eb, nb) hit matrix
-_MINMAX_FCHUNK = 8
-# feature-tile width of the vector grid: one MXU-friendly 128-lane register
+# packed rows per grid step: the f32 sublane tile
+ROWS = 8
+# feature-tile width of the vector grid: one 128-lane register
 FEAT_TILE = 128
 
 
@@ -57,94 +72,90 @@ def sentinels(dtype):
     return NEG, POS
 
 
-def _kernel(vals_ref, idx_ref, out_ref, *, op: str, nb: int):
-    vals = vals_ref[0, :]                       # (Eb,)
-    idx = idx_ref[0, :]                         # (Eb,) local dst in [0, nb)
-    eb = vals.shape[0]
-    neg, pos = sentinels(vals.dtype)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (eb, nb), 1)
-    hit = idx[:, None] == cols
+def _combine_tile(vals, idx, op: str, nb: int):
+    """(k, Eb) values + (k, Eb) block-local indices -> (k, Nb) combined
+    rows.  Sums accumulate in f32 (floats) or int32 (ints)."""
+    k, eb = vals.shape
     if op == "sum":
         acc = (jnp.int32 if jnp.issubdtype(vals.dtype, jnp.integer)
                else jnp.float32)
-        onehot = hit.astype(vals.dtype)
-        out_ref[0, :] = jax.lax.dot_general(
-            vals[None, :], onehot, (((1,), (0,)), ((), ())),
-            preferred_element_type=acc)[0].astype(out_ref.dtype)
-    elif op == "min":
-        out_ref[0, :] = jnp.min(
-            jnp.where(hit, vals[:, None], jnp.asarray(pos, vals.dtype)),
-            axis=0)
-    else:  # max
-        out_ref[0, :] = jnp.max(
-            jnp.where(hit, vals[:, None], jnp.asarray(neg, vals.dtype)),
-            axis=0)
+        vals = vals.astype(acc)
+        fill, red = jnp.asarray(0, acc), jnp.sum
+    else:
+        neg, pos = sentinels(vals.dtype)
+        fill = jnp.asarray(pos if op == "min" else neg, vals.dtype)
+        red = jnp.min if op == "min" else jnp.max
+    cols = jax.lax.broadcasted_iota(jnp.int32, (k, eb, nb), 2)
+    hit = idx[:, :, None] == cols
+    return red(jnp.where(hit, vals[:, :, None], fill), axis=1)
+
+
+def _kernel(vals_ref, idx_ref, out_ref, *, op: str, nb: int):
+    out_ref[...] = _combine_tile(vals_ref[...], idx_ref[...], op,
+                                 nb).astype(out_ref.dtype)
 
 
 def _kernel_vec(vals_ref, idx_ref, out_ref, *, op: str, nb: int):
-    """Feature-blocked twin of ``_kernel``: one (edge block, feature tile)
-    grid step combines an (Eb, ft) value tile into an (nb, ft) output tile.
-    Features are independent, so the (Eb, nb) hit matrix is shared across
-    the tile; min/max walk the tile in ``_MINMAX_FCHUNK`` column chunks so
-    the (Eb, nb, fc) select never outgrows VMEM."""
-    vals = vals_ref[0]                          # (Eb, ft)
-    idx = idx_ref[0]                            # (Eb,)
-    eb, ft = vals.shape
-    neg, pos = sentinels(vals.dtype)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (eb, nb), 1)
-    hit = idx[:, None] == cols
-    if op == "sum":
-        acc = (jnp.int32 if jnp.issubdtype(vals.dtype, jnp.integer)
-               else jnp.float32)
-        onehot = hit.astype(vals.dtype)
-        # out[n, f] = sum_e onehot[e, n] * vals[e, f]  (MXU contraction)
-        out_ref[0] = jax.lax.dot_general(
-            onehot, vals, (((0,), (0,)), ((), ())),
-            preferred_element_type=acc).astype(out_ref.dtype)
-        return
-    fill = jnp.asarray(pos if op == "min" else neg, vals.dtype)
-    red = jnp.min if op == "min" else jnp.max
-    outs = []
-    for f0 in range(0, ft, _MINMAX_FCHUNK):
-        v = vals[:, f0:f0 + _MINMAX_FCHUNK]     # (Eb, fc)
-        outs.append(red(jnp.where(hit[:, :, None], v[:, None, :], fill),
-                        axis=0))
-    out_ref[0] = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+    """Feature-major twin of ``_kernel``: vals (ROWS, ft, Eb), out
+    (ROWS, ft, Nb).  Each row's index vector is shared by its features,
+    so an 8-feature chunk combines as one (8, Eb) tile."""
+    rows, ft, eb = vals_ref.shape
+    n_fc = ft // ROWS
+
+    def body(i, carry):  # a loop, not an unroll: one tile's VMEM live
+        r = i // n_fc
+        f0 = pl.multiple_of((i % n_fc) * ROWS, ROWS)
+        idx = jnp.broadcast_to(idx_ref[pl.ds(r, 1), :], (ROWS, eb))
+        out_ref[r, pl.ds(f0, ROWS), :] = _combine_tile(
+            vals_ref[r, pl.ds(f0, ROWS), :], idx, op,
+            nb).astype(out_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, rows * n_fc, body, 0)
 
 
 def segment_combine_blocks(vals: jax.Array, idx: jax.Array, op: str,
-                           nb: int, interpret: bool = True) -> jax.Array:
+                           nb: int,
+                           interpret: Optional[bool] = None) -> jax.Array:
     """vals: (n_blocks, Eb) or feature-blocked (n_blocks, Eb, F);
     idx: (n_blocks, Eb).  Returns (n_blocks, nb) / (n_blocks, nb, F)
     combined blocks.  idx entries are block-local destinations; padding
-    idx = -1 (never hits).  Scalar input takes the original 2-D kernel
-    unchanged (the F=1 bitwise-identity contract); vector input runs a
-    (block, feature-tile) grid with an inner chunk loop.
-    """
+    idx = -1 (never hits).  Rows are independent: a row's result does
+    not depend on which tile it lands in or on the padding rows."""
+    interpret = resolve_interpret(interpret)
+    n_blocks, eb = idx.shape
+    n_pad = -(-n_blocks // ROWS) * ROWS
+    if n_pad != n_blocks:
+        idx = jnp.pad(idx, ((0, n_pad - n_blocks), (0, 0)),
+                      constant_values=-1)
     if vals.ndim == 3:
-        n_blocks, eb, F = vals.shape
-        ft = min(F, FEAT_TILE)
+        F = vals.shape[2]
+        ft = min(-(-F // ROWS) * ROWS, FEAT_TILE)
         n_ft = -(-F // ft)
         Fp = n_ft * ft
-        if Fp != F:  # pad the tail tile; features never mix, slice after
-            vals = jnp.pad(vals, ((0, 0), (0, 0), (0, Fp - F)))
+        # features ahead of edges; pad rows and the tail feature tile
+        # (features never mix, padding is sliced off after)
+        vt = jnp.pad(jnp.swapaxes(vals, 1, 2),
+                     ((0, n_pad - n_blocks), (0, Fp - F), (0, 0)))
         out = pl.pallas_call(
             functools.partial(_kernel_vec, op=op, nb=nb),
-            grid=(n_blocks, n_ft),
-            in_specs=[pl.BlockSpec((1, eb, ft), lambda i, j: (i, 0, j)),
-                      pl.BlockSpec((1, eb), lambda i, j: (i, 0))],
-            out_specs=pl.BlockSpec((1, nb, ft), lambda i, j: (i, 0, j)),
-            out_shape=jax.ShapeDtypeStruct((n_blocks, nb, Fp), vals.dtype),
+            grid=(n_pad // ROWS, n_ft),
+            in_specs=[pl.BlockSpec((ROWS, ft, eb), lambda i, j: (i, j, 0)),
+                      pl.BlockSpec((ROWS, eb), lambda i, j: (i, 0))],
+            out_specs=pl.BlockSpec((ROWS, ft, nb), lambda i, j: (i, j, 0)),
+            out_shape=jax.ShapeDtypeStruct((n_pad, Fp, nb), vals.dtype),
             interpret=interpret,
-        )(vals, idx)
-        return out[:, :, :F] if Fp != F else out
-    n_blocks, eb = vals.shape
-    return pl.pallas_call(
+        )(vt, idx)
+        return jnp.swapaxes(out[:n_blocks, :F], 1, 2)
+    if n_pad != n_blocks:
+        vals = jnp.pad(vals, ((0, n_pad - n_blocks), (0, 0)))
+    out = pl.pallas_call(
         functools.partial(_kernel, op=op, nb=nb),
-        grid=(n_blocks,),
-        in_specs=[pl.BlockSpec((1, eb), lambda i: (i, 0)),
-                  pl.BlockSpec((1, eb), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, nb), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_blocks, nb), vals.dtype),
+        grid=(n_pad // ROWS,),
+        in_specs=[pl.BlockSpec((ROWS, eb), lambda i: (i, 0)),
+                  pl.BlockSpec((ROWS, eb), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((ROWS, nb), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_pad, nb), vals.dtype),
         interpret=interpret,
     )(vals, idx)
+    return out[:n_blocks] if n_pad != n_blocks else out

@@ -1,6 +1,8 @@
 """jit'd wrapper + host-side edge packing for the segment_combine kernel."""
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import numpy as np
 
@@ -62,7 +64,7 @@ def pack_values(vals: np.ndarray, order: np.ndarray, idx_local: np.ndarray,
 
 def segment_combine(packed_vals: jax.Array, packed_idx: jax.Array, op: str,
                     nb: int, n_out: int, use_kernel: bool = True,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """Combine packed edge messages into (n_out,) destination values —
     or (n_out, F) when ``packed_vals`` carries a feature axis."""
     fn = segment_combine_blocks if use_kernel else segment_combine_blocks_ref
@@ -76,7 +78,7 @@ def segment_combine(packed_vals: jax.Array, packed_idx: jax.Array, op: str,
 def segment_combine_rows(packed_vals: jax.Array, packed_idx: jax.Array,
                          rows: jax.Array, op: str, nb: int,
                          use_kernel: bool = True,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: Optional[bool] = None) -> jax.Array:
     """Block-subset entry point: combine only the ``rows`` subset of a
     packed layout, returning their (len(rows), nb) combined blocks.
 

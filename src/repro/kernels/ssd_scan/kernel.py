@@ -10,11 +10,14 @@ update is one more.  VMEM per step: Q*(P+2N) inputs + Q*Q decay + P*N state
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
 def _kernel(x_ref, dt_ref, A_ref, B_ref, C_ref, y_ref, state_ref, *,
@@ -60,9 +63,11 @@ def _kernel(x_ref, dt_ref, A_ref, B_ref, C_ref, y_ref, state_ref, *,
     y_ref[0] = y.astype(y_ref.dtype)
 
 
-def ssd_scan_bh(x, dt, A, B, C, *, chunk: int, interpret: bool = True):
+def ssd_scan_bh(x, dt, A, B, C, *, chunk: int,
+                interpret: Optional[bool] = None):
     """x: (BH, S, P); dt: (BH, S); A: (BH,); B, C: (BH, S, N).
     Returns y: (BH, S, P)."""
+    interpret = resolve_interpret(interpret)
     BH, S, P = x.shape
     N = B.shape[-1]
     assert S % chunk == 0

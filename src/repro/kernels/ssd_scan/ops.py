@@ -11,7 +11,7 @@ from repro.kernels.ssd_scan.ref import ssd_scan_ref
 
 
 @partial(jax.jit, static_argnames=("chunk", "use_kernel", "interpret"))
-def ssd_scan(x, dt, A, B, C, *, chunk=128, use_kernel=True, interpret=True):
+def ssd_scan(x, dt, A, B, C, *, chunk=128, use_kernel=True, interpret=None):
     """Model layout: x (b, s, h, p); dt (b, s, h); A (h,); B/C (b, s, g, n)
     with g == 1 (groups broadcast outside).  Returns y (b, s, h, p)."""
     b, s, h, p = x.shape

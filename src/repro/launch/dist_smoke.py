@@ -13,14 +13,16 @@ jaxlib's CPU backend cannot *execute* multi-process computations (no
 cross-process CPU collective transport in this build: execution fails
 with ``Multiprocess computations aren't implemented on the CPU
 backend``), so on CPU-only machines the smoke verifies the coordinator
-handshake + global device enumeration and then SKIPS the execution leg,
-exiting 0.  On a real multi-host accelerator fleet the same entrypoint
-runs the full parity check.
+handshake + global device enumeration and then SKIPS the execution leg
+with exit code 3 — a skip is never reported as a pass.  On a real
+multi-host accelerator fleet the same entrypoint runs the full parity
+check.
 
     PYTHONPATH=src python -m repro.launch.dist_smoke
 
-Exit codes: 0 = parity OK or graceful CPU-backend skip; 1 = real
-failure (handshake broke, wrong device counts, or parity violated).
+Exit codes: 0 = parity OK; 3 = execution leg skipped (CPU backend);
+1 = real failure (handshake broke, wrong device counts, or parity
+violated).
 """
 from __future__ import annotations
 
@@ -30,6 +32,7 @@ import subprocess
 import sys
 
 _CPU_UNSUPPORTED = "Multiprocess computations aren't implemented"
+SKIP = 3
 
 
 def _worker(rank: int, hosts: int, per_host: int, port: int, n: int,
@@ -68,7 +71,7 @@ def _worker(rank: int, hosts: int, per_host: int, port: int, n: int,
                   f"jaxlib cannot run multi-process computations on the "
                   f"CPU backend (handshake + enumeration verified)",
                   flush=True)
-            return 0
+            return SKIP
         raise
     ok = (np.array_equal(np.asarray(lab), np.asarray(ref))
           and all(np.array_equal(np.asarray(stats[k]),
@@ -111,7 +114,9 @@ def main() -> None:
             p.kill()
             codes.append(124)
     print(f"[dist_smoke] worker exit codes: {codes}")
-    sys.exit(0 if all(c == 0 for c in codes) else 1)
+    if all(c == 0 for c in codes):
+        sys.exit(0)
+    sys.exit(SKIP if all(c in (0, SKIP) for c in codes) else 1)
 
 
 if __name__ == "__main__":

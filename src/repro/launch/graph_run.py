@@ -117,6 +117,8 @@ def main():
         force_host_devices(args.devices)
 
     # jax initializes on first repro import — after the flags above
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import numpy as np
     from repro.api import Engine
     from repro.core.cost_model import straggler_report

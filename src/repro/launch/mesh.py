@@ -2,21 +2,27 @@
 
 A FUNCTION, not a module-level constant, so importing this module never
 touches jax device state (the dry-run sets XLA_FLAGS before first init).
+
+Every mesh is built here with Auto axes: ``jax.make_mesh`` otherwise
+defaults to Explicit axes, under which host-side slicing of a sharded
+result and jitting over sharded inputs raise ``ShardingTypeError``.
 """
 from __future__ import annotations
 
 import jax
 
 
+def make_mesh(shape, axes):
+    """Auto-axis mesh over the visible devices (tests / elastic re-mesh)."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh (tests / elastic re-mesh)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return make_mesh(shape, axes)
 
 
 def graph_mesh(hosts: int, per_host: int):
@@ -42,7 +48,7 @@ def graph_mesh(hosts: int, per_host: int):
         raise RuntimeError(
             f"graph_mesh({hosts}, {per_host}) needs {need} devices but "
             f"only {len(jax.devices())} are visible")
-    return jax.make_mesh((hosts, per_host), ("h", "w"))
+    return make_mesh((hosts, per_host), ("h", "w"))
 
 
 def dp_axes(mesh) -> tuple:

@@ -1,7 +1,7 @@
 """Persistent graph-service demo — the acceptance workload.
 
     PYTHONPATH=src python -m repro.launch.serve_graph \
-        --n 200000 --devices 8 --workers 32
+        --n 200000 --devices 1 --workers 32
 
 Boots a :class:`repro.core.service.GraphService` holding a resident
 partitioned + sharded powerlaw graph, then:
@@ -30,7 +30,9 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--n", type=int, default=200_000)
     ap.add_argument("--avg-deg", type=float, default=8.0)
     ap.add_argument("--workers", type=int, default=32)
-    ap.add_argument("--devices", type=int, default=8)
+    ap.add_argument("--devices", type=int, default=1,
+                    help="shard the resident graph over this many "
+                         "devices (on CPU, forced host devices)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--batch", type=int, default=64,
                     help="queries per mixed batch")
@@ -81,6 +83,8 @@ def main():
         from repro.launch.xla_flags import force_host_devices
         force_host_devices(args.devices)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import numpy as np
     from repro.api import Engine, EngineConfig
     from repro.core.service import GraphClient, GraphService, Query

@@ -28,7 +28,6 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -113,11 +112,11 @@ def embed_lookup_sharded(table: jax.Array, ids: jax.Array, mesh,
         out = jnp.take(resp, inv, axis=0)              # local scatter
         return out.reshape(ids_loc.shape[0], S, D)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp_axes, None), P(mp_axis, None)),
         out_specs=P(dp_axes, None, None),
-        check_rep=False,
+        check_vma=False,
     )(ids, table)
 
 

@@ -167,13 +167,12 @@ def moe_ffn_ep(x: jax.Array, w: dict, cfg: MoEConfig, ctx: MoEContext) -> tuple:
     tok_spec = P((*dp, ep), None)
     exp_spec = P(ep, None, None)
     rep = P(None, None, None)
-    from jax.experimental.shard_map import shard_map
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(tok_spec, P(None, None), exp_spec, exp_spec, exp_spec,
                   rep, rep, rep),
         out_specs=(tok_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(x, w["router"], w["w_gate"], w["w_up"], w["w_down"],
       w["w_gate_m"], w["w_up_m"], w["w_down_m"])
     return y, aux

@@ -16,7 +16,7 @@ Differentiation inside ``shard_map`` follows the executor's gradient
 contract (verified by tests/test_gspmm.py):
 
 * the loss each device differentiates is its LOCAL masked sum — never a
-  ``psum``.  Differentiating through ``psum`` under ``check_rep=False``
+  ``psum``.  Differentiating through ``psum`` under ``check_vma=False``
   multiplies cotangents by the device count; and no psum is needed,
   because the join's backward pass is itself a collective that routes
   every device's cotangent contributions to the owning rows.

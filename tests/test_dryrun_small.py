@@ -32,7 +32,8 @@ def test_train_step_lowers_sharded():
                                             make_train_step)
         from repro.models import model_zoo as zoo
         from repro.configs.base import ShapeConfig
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = dataclasses.replace(
             get_config("tinyllama_1_1b").reduced(), vocab=256)
         ctx = ModelContext(mesh=mesh, dp_axes=("data",), remat="full",
@@ -70,7 +71,8 @@ def test_decode_step_lowers_with_cache_specs():
         from repro.models import model_zoo as zoo
         from repro.models.transformer import ModelContext
         from repro.train.train_step import make_decode_step
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = dataclasses.replace(
             get_config("gemma3_4b").reduced(), vocab=256)
         ctx = ModelContext(mesh=mesh, dp_axes=("data",), q_chunk=16,

@@ -75,6 +75,7 @@ def test_moe_ep_matches_ref_multidevice():
         sys.path.insert(0, "src")
         from repro.configs.base import MoEConfig
         from repro.models.moe import moe_ffn_ref, moe_ffn_ep, MoEContext
+        from repro.launch.mesh import make_mesh
         key = jax.random.PRNGKey(0)
         T, D, E, F = 64, 16, 8, 32
         ks = jax.random.split(key, 7)
@@ -96,7 +97,7 @@ def test_moe_ep_matches_ref_multidevice():
         cfg = MoEConfig(n_experts=E, top_k=2, d_ff_expert=F,
                         capacity_factor=50.0, n_mirrored_experts=0)
         y_ref, aux_ref = moe_ffn_ref(x, w, cfg)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = MoEContext(mesh=mesh, ep_axis="model", dp_axes=("data",))
         y_ep, aux_ep = jax.jit(lambda x: moe_ffn_ep(x, w, cfg, ctx))(x)
         err = float(jnp.abs(y_ref - y_ep).max())

@@ -97,6 +97,33 @@ def test_csr_equals_padded_rows_concatenated():
         assert (np.asarray(pc.all_src[sl]) // n_loc == w).all()
 
 
+EDGE_FIELDS = ("eg_src", "eg_dst", "eg_mask", "eg_w", "all_src", "all_dst",
+               "all_mask", "all_w", "mir_esrc", "mir_edst", "mir_emask",
+               "mir_ew", "eg_pw", "all_pw", "mir_pw")
+
+
+@pytest.mark.parametrize("layout,balance", [("padded", "hash"),
+                                            ("csr", "hash"),
+                                            ("csr", "split"),
+                                            ("csr", "fold")])
+def test_edge_arrays_stay_on_host(layout, balance):
+    """Edge-sized arrays are host NumPy, fresh and after a fold: the
+    sharded executor hands each device its slice, so none may sit whole
+    on the default device."""
+    from repro.graph.structs import EdgeDelta, fold_delta
+    g = gen.powerlaw(300, avg_deg=6, seed=5, weighted=True).symmetrized()
+    pg = partition(g, 4, tau=8, seed=0, layout=layout,
+                   balance="hash" if balance == "fold" else balance)
+    if balance == "fold":
+        pg = fold_delta(pg, EdgeDelta(add_src=np.array([0, 1]),
+                                      add_dst=np.array([2, 3]),
+                                      add_w=np.ones(2, np.float32),
+                                      rem_src=g.src[:3], rem_dst=g.dst[:3]))
+    for name in EDGE_FIELDS:
+        v = getattr(pg, name)
+        assert v is None or type(v) is np.ndarray, (name, type(v))
+
+
 def test_partition_rejects_unknown_layout():
     g = gen.chain(16)
     with pytest.raises(ValueError):

@@ -48,7 +48,8 @@ def test_graph_engine_lowers_on_mesh():
         from repro.core.channels import broadcast
         g = gen.powerlaw(4000, avg_deg=6, seed=0).symmetrized()
         pg = partition(g, 8, tau=32, seed=0)
-        mesh = jax.make_mesh((8,), ("w",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("w",))
         sh = NamedSharding(mesh, P("w"))
         def superstep(vals, active):
             return broadcast(pg, vals, active, op="min", use_mirroring=True)

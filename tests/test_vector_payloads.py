@@ -55,7 +55,10 @@ def test_vector_blocks_match_per_feature_scalar(op):
     for f in range(F):
         col = np.asarray(segment_combine_blocks(
             jnp.asarray(vals[:, :, f]), jnp.asarray(idx), op, nb))
-        np.testing.assert_array_equal(out[:, :, f], col)
+        if op == "sum":  # float adds may reassociate across tile shapes
+            np.testing.assert_allclose(out[:, :, f], col, rtol=1e-6)
+        else:
+            np.testing.assert_array_equal(out[:, :, f], col)
 
 
 @pytest.mark.parametrize("op", ["sum", "min", "max"])
