@@ -1,0 +1,10 @@
+"""Device (TPU): share of the traced window in which no op ran, one
+minus the union of busy intervals over the window."""
+UNIT = "%"
+
+
+def read(rec):
+    t = rec["trace"]
+    if t is None or not t["window_s"]:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
