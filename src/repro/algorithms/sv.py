@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from repro.api import EngineConfig, RunResult, warn_legacy
 from repro.core import bsp
 from repro.core import exec as exec_mod
+from repro.core import spans
 from repro.core.channels import broadcast, gather, scatter_state
 from repro.core.plan import identity_of
 from repro.graph.structs import PartitionedGraph
@@ -27,15 +28,16 @@ from repro.graph.structs import PartitionedGraph
 
 def _acc(stats, s, workers):
     """Accumulate a channel stats dict into uniform rr/basic counters."""
-    rr = s.get("msgs_rr", s.get("msgs_combined", 0))
-    stats["msgs_rr"] = stats.get("msgs_rr", 0) + rr
-    stats["msgs_basic"] = stats.get("msgs_basic", 0) + s["msgs_basic"]
-    pw_rr = s.get("per_worker_rr", s.get("per_worker_combined"))
-    stats["per_worker_rr"] = stats.get("per_worker_rr",
-                                       jnp.zeros(workers, jnp.int32)) + pw_rr
-    stats["per_worker_basic"] = (stats.get("per_worker_basic",
-                                           jnp.zeros(workers, jnp.int32))
-                                 + s["per_worker_basic"])
+    with spans.scope(spans.STATS):
+        rr = s.get("msgs_rr", s.get("msgs_combined", 0))
+        stats["msgs_rr"] = stats.get("msgs_rr", 0) + rr
+        stats["msgs_basic"] = stats.get("msgs_basic", 0) + s["msgs_basic"]
+        pw_rr = s.get("per_worker_rr", s.get("per_worker_combined"))
+        stats["per_worker_rr"] = stats.get(
+            "per_worker_rr", jnp.zeros(workers, jnp.int32)) + pw_rr
+        stats["per_worker_basic"] = (stats.get("per_worker_basic",
+                                               jnp.zeros(workers, jnp.int32))
+                                     + s["per_worker_basic"])
     return stats
 
 

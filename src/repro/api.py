@@ -32,6 +32,7 @@ from typing import Any, Optional, Tuple, Union
 
 import numpy as np
 
+from repro.core import spans
 from repro.graph import structs
 
 #: algo name -> (module, canonical entry point).  Imports are lazy so
@@ -139,6 +140,7 @@ class Engine:
                                  split_factor=cfg.split_factor,
                                  hosts=cfg.hosts, perm=perm)
 
+    @spans.traced(spans.ENGINE_RUN)
     def run(self, algo: str, graph, M: Optional[int] = None,
             tau: Optional[int] = None, seed: int = 0,
             **algo_params) -> RunResult:

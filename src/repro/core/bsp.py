@@ -38,6 +38,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans
+
 _SIGN = -2 ** 31  # int32 sign bit: xor flips signed compare into unsigned
 
 
@@ -131,16 +133,18 @@ def run(step: Callable, state, max_supersteps: int,
 
     def body(carry):
         st, _, i, acc, hist, pending = carry
-        st, halted, stats = step(st, i)
+        with spans.scope(spans.SUPERSTEP):
+            st, halted, stats = step(st, i)
         leaves = jax.tree.leaves(stats)
-        if pipeline:
-            # fold the PREVIOUS superstep's counts while this superstep's
-            # exchange is still in flight; stash this one for the next
-            # iteration (or the epilogue)
-            acc = acc_add(acc, pending)
-            pending = leaves
-        else:
-            acc = acc_add(acc, leaves)
+        with spans.scope(spans.STATS):
+            if pipeline:
+                # fold the PREVIOUS superstep's counts while this
+                # superstep's exchange is still in flight; stash this one
+                # for the next iteration (or the epilogue)
+                acc = acc_add(acc, pending)
+                pending = leaves
+            else:
+                acc = acc_add(acc, leaves)
         if record_history:
             hist = jax.tree.map(lambda h, s: h.at[i].set(s), hist, stats)
         return st, halted, i + 1, acc, hist, pending
@@ -149,7 +153,8 @@ def run(step: Callable, state, max_supersteps: int,
              zero_acc, history0, zero_pending)
     st, _, n, acc, hist, pending = jax.lax.while_loop(cond, body, carry)
     if pipeline:
-        acc = acc_add(acc, pending)          # the last deferred superstep
+        with spans.scope(spans.STATS):
+            acc = acc_add(acc, pending)      # the last deferred superstep
     if raw_totals:
         return st, acc, n, hist
     return st, finalize_totals(acc, treedef), n, hist

@@ -97,6 +97,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import bsp
 from repro.core import cost_model
 from repro.core import plan as planlib
+from repro.core import spans
 from repro.core.channels import _dedup_row, relay_values
 from repro.core.plan import identity_of, scatter_op
 from repro.launch import mesh as meshlib
@@ -650,6 +651,7 @@ def _build_fetch_plan_hier(need_lists, loc_n: int, H: int, T: int,
                   "b_send": b_send, "b_recv": b_recv}
 
 
+@spans.scope(spans.EXCHANGE)
 def _fetch_planned(sg, fp: TracedFetch, flat_vals: jnp.ndarray, fill
                    ) -> jnp.ndarray:
     """Run one static fetch plan: returns my compact (n_need,) value
@@ -824,6 +826,7 @@ def _cap_hints_2d(pg, D: int, H: int, T: int
     return hint_w, hint_h
 
 
+@spans.traced(spans.SHARD_GRAPH)
 def _shard_graph(pg, devices, plan_kinds: Sequence[str],
                  pipeline: bool = False,
                  pipeline_chunks: Optional[int] = None):
@@ -950,9 +953,10 @@ def _shard_graph(pg, devices, plan_kinds: Sequence[str],
     specs["mir_cesrc"] = P(AXIS)
 
     for kind in plan_kinds:
-        pmeta, parrs = _stack_plans(
-            _device_plans(pg, D, kind, planlib.default_nb()), m,
-            chunks=chunks, hier=hier)
+        with spans.span(spans.PLAN):
+            pmeta, parrs = _stack_plans(
+                _device_plans(pg, D, kind, planlib.default_nb()), m,
+                chunks=chunks, hier=hier)
         meta["plan_meta"][kind] = pmeta
         for k, v in parrs.items():
             arrays[f"plan_{kind}_{k}"] = v
@@ -1419,6 +1423,7 @@ def _pipeline_cap(sg: ShardedGraph, cap: int, feat_elems: int = 1) -> int:
     return min(cap, max(8, _pad8(-(-cap // chunks))))
 
 
+@spans.scope(spans.EXCHANGE)
 def _routed_scatter_combine(sg: ShardedGraph, targets, values, valid,
                             op: str, cap: Optional[int] = None
                             ) -> jnp.ndarray:
@@ -1511,6 +1516,7 @@ def _bucket_level(sg: ShardedGraph, targets, valid, level: str):
     return order, off
 
 
+@spans.scope(spans.EXCHANGE)
 def _hier_scatter_combine(sg: ShardedGraph, targets, values, valid,
                           op: str, cap=None) -> jnp.ndarray:
     """2-D twin of :func:`_routed_scatter_combine`: lanes first route to
@@ -1592,6 +1598,7 @@ def _hier_scatter_combine(sg: ShardedGraph, targets, values, valid,
     return jax.lax.fori_loop(0, rounds1, outer, buf0)
 
 
+@spans.scope(spans.EXCHANGE)
 def _routed_fetch(sg: ShardedGraph, vals, targets, valid,
                   cap: Optional[int] = None) -> jnp.ndarray:
     """The request-respond transport: a real two-round trip.  (L,) global
@@ -1651,6 +1658,7 @@ def _routed_fetch(sg: ShardedGraph, vals, targets, valid,
     return jnp.where(planlib.feat_mask(ok_t, got, 1), got, zero)
 
 
+@spans.scope(spans.EXCHANGE)
 def _hier_routed_fetch(sg: ShardedGraph, vals, targets, valid,
                        cap=None) -> jnp.ndarray:
     """2-D twin of :func:`_routed_fetch`: requests first route to the
@@ -1742,6 +1750,7 @@ def _hier_routed_fetch(sg: ShardedGraph, vals, targets, valid,
 # sharded channel implementations
 # ---------------------------------------------------------------------------
 
+@spans.scope(spans.EXCHANGE)
 def _plan_exchange_pipelined(sg: ShardedGraph, plan: TracedPlan,
                              flat_vals: jnp.ndarray, op: str,
                              loc: jnp.ndarray, ident) -> jnp.ndarray:
@@ -1755,18 +1764,21 @@ def _plan_exchange_pipelined(sg: ShardedGraph, plan: TracedPlan,
 
     def send(c):
         rows_ok = plan.crow_ok[c]
-        row_out = planlib.combine_rows_subset(
-            plan, flat_vals, plan.crow[c], rows_ok, op)
-        sbuf = jnp.full((plan.cs, plan.nb) + feat, ident, flat_vals.dtype)
-        seg_out = scatter_op(
-            op, sbuf, jnp.where(rows_ok, plan.crow_seg[c], 0),
-            jnp.where(planlib.feat_mask(rows_ok[:, None], row_out, 2),
-                      row_out, ident))
+        with spans.scope(spans.COMBINE):
+            row_out = planlib.combine_rows_subset(
+                plan, flat_vals, plan.crow[c], rows_ok, op)
+            sbuf = jnp.full((plan.cs, plan.nb) + feat, ident,
+                            flat_vals.dtype)
+            seg_out = scatter_op(
+                op, sbuf, jnp.where(rows_ok, plan.crow_seg[c], 0),
+                jnp.where(planlib.feat_mask(rows_ok[:, None], row_out, 2),
+                          row_out, ident))
         g = seg_out[plan.cxseg[c]]
         snd = jnp.where(planlib.feat_mask(plan.cxval[c][:, :, None], g, 3),
                         g, ident)
         return jax.lax.all_to_all(snd, sg.axis, 0, 0)
 
+    @spans.scope(spans.COMBINE)
     def combine(buf, c, recv):
         return scatter_op(
             op, buf, jnp.where(plan.crval[c], plan.crblk[c], 0),
@@ -1781,6 +1793,7 @@ def _plan_exchange_pipelined(sg: ShardedGraph, plan: TracedPlan,
     return combine(loc, plan.n_chunks - 1, recv)
 
 
+@spans.scope(spans.EXCHANGE)
 def _plan_exchange_hier(sg: ShardedGraph, plan: TracedPlan,
                         seg_out: jnp.ndarray, op: str,
                         loc: jnp.ndarray, ident) -> jnp.ndarray:
@@ -1874,11 +1887,12 @@ def _combine_with_plan_sharded(sg: ShardedGraph, plan: TracedPlan,
                 loc = _plan_exchange_hier(sg, plan, seg_out, op, loc,
                                           ident)
             else:
-                g = seg_out[plan.xseg]
-                send = jnp.where(
-                    planlib.feat_mask(plan.xval[:, :, None], g, 3),
-                    g, ident)
-                recv = jax.lax.all_to_all(send, sg.axis, 0, 0)
+                with spans.scope(spans.EXCHANGE):
+                    g = seg_out[plan.xseg]
+                    send = jnp.where(
+                        planlib.feat_mask(plan.xval[:, :, None], g, 3),
+                        g, ident)
+                    recv = jax.lax.all_to_all(send, sg.axis, 0, 0)
                 loc = scatter_op(
                     op, loc, jnp.where(plan.rval, plan.rblk, 0),
                     jnp.where(
@@ -1895,14 +1909,15 @@ def _combine_with_plan_sharded(sg: ShardedGraph, plan: TracedPlan,
     stats = None
     if count_cross:
         # mask-driven accounting (TracedPlan duck-types EdgePlan here)
-        sh = planlib.plan_seg_hits(plan, flat_hits)
-        seg_log = sg.log_of(plan.seg_worker)
-        owner = plan.seg_blk // plan.B_per_w
-        cross = sh & (owner != seg_log)[:, None]
-        msgs = jax.lax.psum(cross.sum().astype(jnp.int32), sg.axis)
-        per_worker = jnp.zeros((sg.M,), jnp.int32).at[seg_log].add(
-            cross.sum(axis=1).astype(jnp.int32))
-        stats = (msgs, jax.lax.psum(per_worker, sg.axis))
+        with spans.scope(spans.STATS):
+            sh = planlib.plan_seg_hits(plan, flat_hits)
+            seg_log = sg.log_of(plan.seg_worker)
+            owner = plan.seg_blk // plan.B_per_w
+            cross = sh & (owner != seg_log)[:, None]
+            msgs = jax.lax.psum(cross.sum().astype(jnp.int32), sg.axis)
+            per_worker = jnp.zeros((sg.M,), jnp.int32).at[seg_log].add(
+                cross.sum(axis=1).astype(jnp.int32))
+            stats = (msgs, jax.lax.psum(per_worker, sg.axis))
     return inbox, stats
 
 
@@ -1922,9 +1937,10 @@ def _combine_sorted_rows_sharded(sg: ShardedGraph, targets, values, mask,
     inbox = buf.reshape((sg.m_loc, sg.n_loc)
                         + planlib.feat_shape(values, 2))
 
-    cross = real & (seg_t // sg.n_loc != seg_row + sg.w0)
-    msgs = jax.lax.psum(cross.sum().astype(jnp.int32), sg.axis)
-    per_worker = _scatter_workers(sg, seg_row + sg.w0, cross)
+    with spans.scope(spans.STATS):
+        cross = real & (seg_t // sg.n_loc != seg_row + sg.w0)
+        msgs = jax.lax.psum(cross.sum().astype(jnp.int32), sg.axis)
+        per_worker = _scatter_workers(sg, seg_row + sg.w0, cross)
     return inbox, (msgs, per_worker)
 
 
@@ -1942,10 +1958,11 @@ def _combine_sorted_flat_sharded(sg: ShardedGraph, targets, values, mask,
     inbox = buf.reshape((sg.m_loc, sg.n_loc)
                         + planlib.feat_shape(values, 1))
 
-    seg_log = sg.log_of(jnp.where(real, seg_w, 0))
-    cross = real & (seg_t // sg.n_loc != seg_log)
-    msgs = jax.lax.psum(cross.sum().astype(jnp.int32), sg.axis)
-    per_worker = _scatter_workers(sg, seg_log, cross)
+    with spans.scope(spans.STATS):
+        seg_log = sg.log_of(jnp.where(real, seg_w, 0))
+        cross = real & (seg_t // sg.n_loc != seg_log)
+        msgs = jax.lax.psum(cross.sum().astype(jnp.int32), sg.axis)
+        per_worker = _scatter_workers(sg, seg_log, cross)
     return inbox, (msgs, per_worker)
 
 
@@ -1957,10 +1974,11 @@ def push_combined_sharded(sg: ShardedGraph, targets, values, mask, op: str,
     one (dense backend, runtime targets) through the sorted segmented
     core.  Both exchange destination-routed — inboxes and stats are
     identical to the reference paths (min/max bitwise, stats exact)."""
-    gw = sg.worker_ids()[:, None]
-    raw_cross = mask & ((targets // sg.n_loc) != gw)
-    base = {"msgs_basic": jax.lax.psum(raw_cross.sum(), sg.axis),
-            "per_worker_basic": _place_rows(sg, raw_cross.sum(axis=1))}
+    with spans.scope(spans.STATS):
+        gw = sg.worker_ids()[:, None]
+        raw_cross = mask & ((targets // sg.n_loc) != gw)
+        base = {"msgs_basic": jax.lax.psum(raw_cross.sum(), sg.axis),
+                "per_worker_basic": _place_rows(sg, raw_cross.sum(axis=1))}
 
     if backend == "pallas" and plan is not None:
         ident = identity_of(op, values.dtype)
@@ -1984,10 +2002,11 @@ def push_combined_flat_sharded(sg: ShardedGraph, targets, values, mask,
     per-edge source workers (physical shard ids under a split partition —
     a shard never straddles devices, so the per-device distinct-pair
     accounting composes exactly across any device count)."""
-    wlog = sg.log_of(worker)
-    raw_cross = mask & ((targets // sg.n_loc) != wlog)
-    base = {"msgs_basic": jax.lax.psum(raw_cross.sum(), sg.axis),
-            "per_worker_basic": _scatter_workers(sg, wlog, raw_cross)}
+    with spans.scope(spans.STATS):
+        wlog = sg.log_of(worker)
+        raw_cross = mask & ((targets // sg.n_loc) != wlog)
+        base = {"msgs_basic": jax.lax.psum(raw_cross.sum(), sg.axis),
+                "per_worker_basic": _scatter_workers(sg, wlog, raw_cross)}
 
     if backend == "pallas" and plan is not None:
         ident = identity_of(op, values.dtype)
@@ -2065,19 +2084,21 @@ def push_mirror_sharded(sg: ShardedGraph, vals, active, op: str,
     # owner-side mask-driven stats: an ACTIVE mirrored vertex is broadcast
     # to its hosting workers whatever its value; each device charges the
     # mirrored vertices it owns and the psum restores the exact totals
-    safe_g = jnp.clip(sg.mir_ids, 0, n_pad - 1)
-    valid = sg.mir_ids < n_pad
-    slot = safe_g - sg.w0 * sg.n_loc
-    owned = (slot >= 0) & (slot < loc_n)
-    act = flat_act[jnp.clip(slot, 0, loc_n - 1)]
-    sent = jnp.where(valid & owned & act, sg.mir_nworkers, 0)
-    msgs = jax.lax.psum(sent.sum(), sg.axis)
-    owner_w = jnp.clip(safe_g // sg.n_loc, 0, sg.M - 1)
-    per_worker = jnp.zeros((sg.M,), sent.dtype).at[owner_w].add(sent)
-    per_worker = jax.lax.psum(per_worker, sg.axis)
+    with spans.scope(spans.STATS):
+        safe_g = jnp.clip(sg.mir_ids, 0, n_pad - 1)
+        valid = sg.mir_ids < n_pad
+        slot = safe_g - sg.w0 * sg.n_loc
+        owned = (slot >= 0) & (slot < loc_n)
+        act = flat_act[jnp.clip(slot, 0, loc_n - 1)]
+        sent = jnp.where(valid & owned & act, sg.mir_nworkers, 0)
+        msgs = jax.lax.psum(sent.sum(), sg.axis)
+        owner_w = jnp.clip(safe_g // sg.n_loc, 0, sg.M - 1)
+        per_worker = jnp.zeros((sg.M,), sent.dtype).at[owner_w].add(sent)
+        per_worker = jax.lax.psum(per_worker, sg.axis)
     return inbox, {"msgs_mirror": msgs, "per_worker_mirror": per_worker}
 
 
+@spans.scope(spans.COMBINE)
 def broadcast_sharded(sg: ShardedGraph, vals, active, op: str,
                       relay: str = "none", use_mirroring: bool = True,
                       backend: str = "dense"):
@@ -2124,15 +2145,17 @@ def broadcast_sharded(sg: ShardedGraph, vals, active, op: str,
                                          backend=backend)
         inbox = _MERGE[op](inbox, inbox2)
         stats.update(s2)
-    else:
-        stats["msgs_mirror"] = jnp.zeros((), jnp.int32)
-        stats["per_worker_mirror"] = jnp.zeros((sg.M,), jnp.int32)
-    stats["msgs_total"] = stats["msgs_combined"] + stats["msgs_mirror"]
-    stats["per_worker_total"] = (stats["per_worker_combined"]
-                                 + stats["per_worker_mirror"])
+    with spans.scope(spans.STATS):
+        if not use_mirroring:
+            stats["msgs_mirror"] = jnp.zeros((), jnp.int32)
+            stats["per_worker_mirror"] = jnp.zeros((sg.M,), jnp.int32)
+        stats["msgs_total"] = stats["msgs_combined"] + stats["msgs_mirror"]
+        stats["per_worker_total"] = (stats["per_worker_combined"]
+                                     + stats["per_worker_mirror"])
     return inbox, stats
 
 
+@spans.scope(spans.REQRESP)
 def gather_sharded(sg: ShardedGraph, vals, targets, tmask,
                    dedup: bool = True):
     """Sharded Ch_req for row-shaped targets (m_loc, R): a real two-round
@@ -2157,23 +2180,26 @@ def gather_sharded(sg: ShardedGraph, vals, targets, tmask,
     out = jnp.where(planlib.feat_mask(tmask, out, 2), out,
                     jnp.zeros((), vals.dtype))
 
-    owner = jnp.clip(uniq // sg.n_loc, 0, sg.M - 1)
-    uvalid = uniq < n_pad
-    self_w = sg.worker_ids()[:, None]
-    remote_u = uvalid & (owner != self_w)
-    raw_remote = tmask & ((targets // sg.n_loc) != self_w)
-    raw_owner = jnp.clip(targets // sg.n_loc, 0, sg.M - 1)
-    stats = {
-        "msgs_rr": 2 * jax.lax.psum(remote_u.sum(), sg.axis),
-        "msgs_basic": 2 * jax.lax.psum(raw_remote.sum(), sg.axis),
-        "per_worker_rr": (_place_rows(sg, remote_u.sum(1))
-                          + _scatter_workers(sg, owner, remote_u)),
-        "per_worker_basic": (_place_rows(sg, raw_remote.sum(1))
-                             + _scatter_workers(sg, raw_owner, raw_remote)),
-    }
+    with spans.scope(spans.STATS):
+        owner = jnp.clip(uniq // sg.n_loc, 0, sg.M - 1)
+        uvalid = uniq < n_pad
+        self_w = sg.worker_ids()[:, None]
+        remote_u = uvalid & (owner != self_w)
+        raw_remote = tmask & ((targets // sg.n_loc) != self_w)
+        raw_owner = jnp.clip(targets // sg.n_loc, 0, sg.M - 1)
+        stats = {
+            "msgs_rr": 2 * jax.lax.psum(remote_u.sum(), sg.axis),
+            "msgs_basic": 2 * jax.lax.psum(raw_remote.sum(), sg.axis),
+            "per_worker_rr": (_place_rows(sg, remote_u.sum(1))
+                              + _scatter_workers(sg, owner, remote_u)),
+            "per_worker_basic": (
+                _place_rows(sg, raw_remote.sum(1))
+                + _scatter_workers(sg, raw_owner, raw_remote)),
+        }
     return out, stats
 
 
+@spans.scope(spans.REQRESP)
 def gather_edges_sharded(sg: ShardedGraph, vals, targets, tmask,
                          dedup: bool = True):
     """Sharded Ch_req for edge-shaped targets (layout-dispatching).  The
@@ -2200,27 +2226,29 @@ def gather_edges_sharded(sg: ShardedGraph, vals, targets, tmask,
     out = jnp.where(planlib.feat_mask(t < n_pad, out, 1), out,
                     jnp.zeros((), vals.dtype))
 
-    owner = jnp.clip(targets // sg.n_loc, 0, sg.M - 1)
-    raw_remote = tmask & ((targets // sg.n_loc) != wlog)
-    if dedup:
-        ws_log = sg.log_of(ws)
-        uniq = heads
-        remote_u = uniq & (ts // sg.n_loc != ws_log)
-        u_w, u_owner = ws_log, jnp.clip(ts // sg.n_loc, 0, sg.M - 1)
-    else:
-        remote_u = raw_remote
-        u_w, u_owner = wlog, owner
-    stats = {
-        "msgs_rr": 2 * jax.lax.psum(remote_u.sum(), sg.axis),
-        "msgs_basic": 2 * jax.lax.psum(raw_remote.sum(), sg.axis),
-        "per_worker_rr": (_scatter_workers(sg, u_w, remote_u)
-                          + _scatter_workers(sg, u_owner, remote_u)),
-        "per_worker_basic": (_scatter_workers(sg, wlog, raw_remote)
-                             + _scatter_workers(sg, owner, raw_remote)),
-    }
+    with spans.scope(spans.STATS):
+        owner = jnp.clip(targets // sg.n_loc, 0, sg.M - 1)
+        raw_remote = tmask & ((targets // sg.n_loc) != wlog)
+        if dedup:
+            ws_log = sg.log_of(ws)
+            uniq = heads
+            remote_u = uniq & (ts // sg.n_loc != ws_log)
+            u_w, u_owner = ws_log, jnp.clip(ts // sg.n_loc, 0, sg.M - 1)
+        else:
+            remote_u = raw_remote
+            u_w, u_owner = wlog, owner
+        stats = {
+            "msgs_rr": 2 * jax.lax.psum(remote_u.sum(), sg.axis),
+            "msgs_basic": 2 * jax.lax.psum(raw_remote.sum(), sg.axis),
+            "per_worker_rr": (_scatter_workers(sg, u_w, remote_u)
+                              + _scatter_workers(sg, u_owner, remote_u)),
+            "per_worker_basic": (_scatter_workers(sg, wlog, raw_remote)
+                                 + _scatter_workers(sg, owner, raw_remote)),
+        }
     return out, stats
 
 
+@spans.scope(spans.COMBINE)
 def scatter_state_sharded(sg: ShardedGraph, base, targets, upd, mask,
                           op: str, backend: str = "dense"):
     """Sharded scatter-op for row-shaped runtime targets (S-V hooking).
@@ -2228,10 +2256,12 @@ def scatter_state_sharded(sg: ShardedGraph, base, targets, upd, mask,
     the sorted segmented combine + destination-routed exchange (the
     reference paths' stats are identical by construction, and min/max
     values are order-exact)."""
-    gw = sg.worker_ids()[:, None]
-    raw_cross = mask & ((targets // sg.n_loc) != gw)
-    bstats = {"msgs_basic": jax.lax.psum(raw_cross.sum(), sg.axis),
-              "per_worker_basic": _place_rows(sg, raw_cross.sum(axis=1))}
+    with spans.scope(spans.STATS):
+        gw = sg.worker_ids()[:, None]
+        raw_cross = mask & ((targets // sg.n_loc) != gw)
+        bstats = {"msgs_basic": jax.lax.psum(raw_cross.sum(), sg.axis),
+                  "per_worker_basic": _place_rows(sg,
+                                                  raw_cross.sum(axis=1))}
     inbox, (msgs, pw) = _combine_sorted_rows_sharded(sg, targets, upd,
                                                      mask, op)
     stats = {"msgs_combined": msgs, "per_worker_combined": pw}
@@ -2239,6 +2269,7 @@ def scatter_state_sharded(sg: ShardedGraph, base, targets, upd, mask,
     return _MERGE[op](base, inbox), stats
 
 
+@spans.scope(spans.COMBINE)
 def scatter_edges_sharded(sg: ShardedGraph, base, targets, upd, mask,
                           op: str, backend: str = "dense"):
     """Sharded scatter-op for edge-shaped runtime targets (MSF election)."""
@@ -2246,10 +2277,12 @@ def scatter_edges_sharded(sg: ShardedGraph, base, targets, upd, mask,
         return scatter_state_sharded(sg, base, targets, upd, mask, op,
                                      backend)
     worker = sg.all_pw if sg.split else sg.all_src // sg.n_loc
-    wlog = sg.log_of(worker)
-    raw_cross = mask & ((targets // sg.n_loc) != wlog)
-    bstats = {"msgs_basic": jax.lax.psum(raw_cross.sum(), sg.axis),
-              "per_worker_basic": _scatter_workers(sg, wlog, raw_cross)}
+    with spans.scope(spans.STATS):
+        wlog = sg.log_of(worker)
+        raw_cross = mask & ((targets // sg.n_loc) != wlog)
+        bstats = {"msgs_basic": jax.lax.psum(raw_cross.sum(), sg.axis),
+                  "per_worker_basic": _scatter_workers(sg, wlog,
+                                                       raw_cross)}
     inbox, (msgs, pw) = _combine_sorted_flat_sharded(sg, targets, upd,
                                                      mask, worker, op)
     stats = {"msgs_combined": msgs, "per_worker_combined": pw}
@@ -2321,8 +2354,9 @@ def build_sharded(pg, make_step: Callable, state0, max_supersteps: int,
     if profile is not None:
         _apply_profile(meta, arrays, profile)
 
-    _, _, stats_shape = jax.eval_shape(make_step(pg), state0,
-                                       jnp.zeros((), jnp.int32))
+    with spans.span(spans.TRACE):
+        _, _, stats_shape = jax.eval_shape(make_step(pg), state0,
+                                           jnp.zeros((), jnp.int32))
     st_specs = _state_specs(state0, pg.M, hier)
     stats_specs = jax.tree.map(lambda _: P(), stats_shape)
     hist_specs = stats_specs if record_history else None
@@ -2359,7 +2393,8 @@ def run_sharded(pg, make_step: Callable, state0, max_supersteps: int,
                                           max_supersteps, record_history,
                                           devices, plan_kinds, pipeline,
                                           pipeline_chunks)
-    st, raw_acc, n, hist = fn(*args)
+    with spans.span(spans.LAUNCH):
+        st, raw_acc, n, hist = fn(*args)
     return st, finalize_stats(raw_acc, stats_shape), n, hist
 
 

@@ -145,6 +145,7 @@ def segment_combine_blocks(vals: jax.Array, idx: jax.Array, op: str,
             out_specs=pl.BlockSpec((ROWS, ft, nb), lambda i, j: (i, j, 0)),
             out_shape=jax.ShapeDtypeStruct((n_pad, Fp, nb), vals.dtype),
             interpret=interpret,
+            name="segment_combine",
         )(vt, idx)
         return jnp.swapaxes(out[:n_blocks, :F], 1, 2)
     if n_pad != n_blocks:
@@ -157,5 +158,6 @@ def segment_combine_blocks(vals: jax.Array, idx: jax.Array, op: str,
         out_specs=pl.BlockSpec((ROWS, nb), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, nb), vals.dtype),
         interpret=interpret,
+        name="segment_combine",
     )(vals, idx)
     return out[:n_blocks] if n_pad != n_blocks else out
