@@ -826,6 +826,27 @@ def _cap_hints_2d(pg, D: int, H: int, T: int
     return hint_w, hint_h
 
 
+def _sharded_plan(pg, D: int, hier: Optional[Tuple[int, int]], kind: str,
+                  chunks: Optional[int]):
+    """The stacked device plan of one kind, ``(meta, arrays)`` as
+    :func:`_stack_plans` returns it, built once per partitioned graph and
+    device layout and memoized on ``pg.plan_cache`` under
+    ``("sharded", kind, D, hier, nb, chunks)`` (``get_plan``'s keys are
+    ``(kind, nb, eb)`` triples, so the two never collide).  The cached
+    arrays are read-only and shared by every later call; callers get a
+    copy of the meta, which they insert into their own."""
+    nb = planlib.default_nb()
+    key = ("sharded", kind, D, hier, nb, chunks)
+    if key not in pg.plan_cache:
+        pmeta, parrs = _stack_plans(_device_plans(pg, D, kind, nb),
+                                    pg.M // D, chunks=chunks, hier=hier)
+        for v in parrs.values():
+            v.flags.writeable = False
+        pg.plan_cache[key] = (pmeta, parrs)
+    pmeta, parrs = pg.plan_cache[key]
+    return dict(pmeta), parrs
+
+
 @spans.traced(spans.SHARD_GRAPH)
 def _shard_graph(pg, devices, plan_kinds: Sequence[str],
                  pipeline: bool = False,
@@ -833,7 +854,11 @@ def _shard_graph(pg, devices, plan_kinds: Sequence[str],
     """Build the device-stacked array pytree + matching PartitionSpecs.
     ``devices`` is an int (1-D mesh) or an ``(H, T)`` pair (2-D
     hierarchical mesh; the flat device order d = h*T + t matches the
-    row-major mesh flattening, so every flat table below stays valid)."""
+    row-major mesh flattening, so every flat table below stays valid).
+    The message plan of each of ``plan_kinds`` comes from
+    :func:`_sharded_plan`, cached on ``pg.plan_cache`` per (kind, D,
+    hier, nb, pipeline chunks); the shard arrays are built anew on every
+    call."""
     D, hier = _normalize_devices(devices)
     M, n_loc = pg.M, pg.n_loc
     m = M // D
@@ -954,9 +979,7 @@ def _shard_graph(pg, devices, plan_kinds: Sequence[str],
 
     for kind in plan_kinds:
         with spans.span(spans.PLAN):
-            pmeta, parrs = _stack_plans(
-                _device_plans(pg, D, kind, planlib.default_nb()), m,
-                chunks=chunks, hier=hier)
+            pmeta, parrs = _sharded_plan(pg, D, hier, kind, chunks)
         meta["plan_meta"][kind] = pmeta
         for k, v in parrs.items():
             arrays[f"plan_{kind}_{k}"] = v

@@ -194,8 +194,12 @@ class PartitionedGraph:
     # hierarchical (H, T) device mesh.  None = host-oblivious order.
     hosts: Optional[int] = None
 
-    # lazily-built message plans (core/plan.py), keyed (kind, nb, eb);
-    # per-instance scratch, never part of equality or the pytree.
+    # lazily-built message plans: core/plan.get_plan's, keyed (kind, nb,
+    # eb), and the sharded executor's stacked per-device plans
+    # (core/exec._sharded_plan, read-only arrays), keyed ("sharded", kind,
+    # D, hier, nb, pipeline chunks).  Valid because the instance is never
+    # mutated (fold_delta / repartition return a new one with an empty
+    # cache); per-instance scratch, never part of equality or the pytree.
     plan_cache: dict = dataclasses.field(default_factory=dict, repr=False,
                                          compare=False)
 
